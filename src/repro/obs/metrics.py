@@ -130,8 +130,6 @@ def sharded_sweep_summary(final: ScenarioState, mesh, *, n_steps: int
     scenario 0, see ``parallel.fleet.pad_batch``) are zero-weighted so
     they never double-count. Counter columns match ``sweep_summary``
     exactly (integer sums); float columns to reduction order."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.parallel import fleet as pfleet
 
     n_shards = mesh.shape[pfleet.SCENARIO_AXIS]
@@ -157,9 +155,9 @@ def sharded_sweep_summary(final: ScenarioState, mesh, *, n_steps: int
         return summed
 
     spec = pfleet.shard_spec()
-    fn = shard_map(block, mesh=mesh,
-                   in_specs=(spec, spec),
-                   out_specs=pfleet.replicated_spec(), check_rep=False)
+    fn = jax.shard_map(block, mesh=mesh,
+                       in_specs=(spec, spec),
+                       out_specs=pfleet.replicated_spec(), check_vma=False)
     return jax.jit(fn)(padded, mask)
 
 

@@ -44,7 +44,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from repro.core import asa
 from repro.core.bins import make_bins
@@ -191,8 +190,8 @@ def _sharded_update_fn(mesh):
 
         return _update_body(table, q, mask, scatter_rows=gather_all)
 
-    fn = shard_map(block, mesh=mesh, in_specs=(rep, spec, spec),
-                   out_specs=rep, check_rep=False)
+    fn = jax.shard_map(block, mesh=mesh, in_specs=(rep, spec, spec),
+                       out_specs=rep, check_vma=False)
     return jax.jit(fn)
 
 

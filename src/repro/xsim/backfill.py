@@ -95,30 +95,45 @@ def _freed_sorted(ends, cores, running):
     return csum[cnt - 1]
 
 
+def _shifted(x, k: int, fill, *, left: bool):
+    """``x`` moved ``k`` lanes along its last axis, ``fill`` shifted in:
+    ``left`` gives x[:, i+k], otherwise x[:, i-k]. Static slices and a
+    concat, which Mosaic lowers at any width."""
+    pad = jnp.full((x.shape[0], k), fill, x.dtype)
+    if left:
+        return jnp.concatenate([x[:, k:], pad], axis=-1)
+    return jnp.concatenate([pad, x[:, :-k]], axis=-1)
+
+
 def _freed_sorted_kernel(ends_ref, cores_ref, freed_ref):
-    """Scan portion of the sorted formulation, on PRE-SORTED (1, N) rows.
+    """Scan portion of the sorted formulation, on PRE-SORTED (R, N) rows.
 
     freed_sorted[k] must be the cores cumsum at the last index of k's
     end-time tie run. With ``csum`` nondecreasing, that value is the
     minimum of ``csum`` over the run-*last* positions at or after k — a
-    suffix-min over ``where(is_last, csum, +inf)``, computed with a
-    log₂(N)-step shift-and-min doubling loop (static slices + concats:
-    no gathers, no negative strides — VPU-friendly and interpretable).
+    suffix-min over ``where(is_last, csum, +inf)``. Both scans are
+    log₂(N)-step shift-and-combine doublings (Mosaic has no cumsum):
+    shift-and-add for the prefix sum, shift-and-min for the suffix-min.
+    The doubled sum is exact, hence equal to ``jnp.cumsum``: core counts
+    are integers and every partial sum stays below 2**24.
     """
-    e = ends_ref[...]                      # (1, N), sorted ascending
-    csum = jnp.cumsum(cores_ref[...], axis=-1)
+    e = ends_ref[...]                      # (R, N), each row ascending
     n = e.shape[-1]
-    nxt = jnp.concatenate(
-        [e[:, 1:], jnp.full((1, 1), -jnp.inf, e.dtype)], axis=-1)
-    is_last = e != nxt                     # last element of each tie run
-    v = jnp.where(is_last, csum, jnp.inf)
+    csum = cores_ref[...]
     k = 1
     while k < n:                           # static unroll: ⌈log₂ N⌉ steps
-        shifted = jnp.concatenate(
-            [v[:, k:], jnp.full((1, k), jnp.inf, v.dtype)], axis=-1)
-        v = jnp.minimum(v, shifted)
+        csum = csum + _shifted(csum, k, 0.0, left=False)
+        k *= 2
+    is_last = e != _shifted(e, 1, -jnp.inf, left=True)  # end of tie run
+    v = jnp.where(is_last, csum, jnp.inf)
+    k = 1
+    while k < n:
+        v = jnp.minimum(v, _shifted(v, k, jnp.inf, left=True))
         k *= 2
     freed_ref[...] = v
+
+
+ROW_TILE = 8  # batch rows per grid program: the f32 sublane tile
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -126,12 +141,18 @@ def freed_matrix(ends, cores, running, *, interpret: bool = False):
     """Batched Pallas path for the sorted scan: (B, N) tables → (B, N).
 
     XLA sorts each row (its sort is the part not worth hand-writing), one
-    grid program per scenario row runs the O(n) cumsum + tie-aware
-    suffix-min in VMEM, and the result scatters back through the inverse
-    permutation. Used on TPU (or under ``interpret`` for tests); the
-    sweep's default CPU path inlines the jnp sorted reference, keeping
-    `schedule_pass` trivially vmap-able. Bit-identical to
-    ``_freed_sorted`` (and to the O(n²) reference on integer cores).
+    grid program per ``ROW_TILE`` scenario rows runs the O(n) cumsum +
+    tie-aware suffix-min in VMEM, and the result scatters back through
+    the inverse permutation. A batch of at least ``ROW_TILE`` rows is
+    padded to a multiple of it with rows that hold no running job (end
+    +inf, cores 0), sliced off afterwards; a smaller batch, such as the
+    single row ``freed_vector`` passes under ``jax.vmap``, is one block
+    of its own height. Both block shapes meet Mosaic's rule that a
+    block's last two dims divide by (8, 128) or equal the array's. Used
+    on TPU (or under ``interpret`` for tests); the sweep's default CPU
+    path inlines the jnp sorted reference, keeping `schedule_pass`
+    trivially vmap-able. Bit-identical to ``_freed_sorted`` (and to the
+    O(n²) reference on integer cores).
     """
     B, N = ends.shape
     e = jnp.where(running.astype(bool), ends, jnp.inf).astype(jnp.float32)
@@ -139,17 +160,20 @@ def freed_matrix(ends, cores, running, *, interpret: bool = False):
     order = jnp.argsort(e, axis=1)
     e_s = jnp.take_along_axis(e, order, axis=1)
     c_s = jnp.take_along_axis(c, order, axis=1)
+    rows = min(B, ROW_TILE)
+    pad = (-B) % rows
+    if pad:
+        e_s = jnp.concatenate([e_s, jnp.full((pad, N), jnp.inf)])
+        c_s = jnp.concatenate([c_s, jnp.zeros((pad, N), jnp.float32)])
+    block = pl.BlockSpec((rows, N), lambda b: (b, 0))
     freed_s = pl.pallas_call(
         _freed_sorted_kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, N), lambda b: (b, 0)),
-            pl.BlockSpec((1, N), lambda b: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, N), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
+        grid=((B + pad) // rows,),
+        in_specs=[block, block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((B + pad, N), jnp.float32),
         interpret=interpret,
-    )(e_s, c_s)
+    )(e_s, c_s)[:B]
     inv = jnp.argsort(order, axis=1)
     return jnp.take_along_axis(freed_s, inv, axis=1)
 
@@ -161,7 +185,7 @@ def freed_vector(ends, cores, running, *, mode: str = "ref"):
     vmap-able). ``ref_n2``: the original O(n²) pairwise reference, kept
     for differential checks. ``interpret``/``tpu``: the sorted Pallas
     kernel, run single-scenario; under ``jax.vmap`` the batching rule
-    turns it into the (B, N) grid.
+    gives it a grid of B one-row (1, N) blocks.
     """
     if mode == "ref":
         return _freed_sorted(ends, cores, running)

@@ -114,16 +114,14 @@ def sharded_batched_metrics(final: ScenarioState, mesh
     the per-scenario sums differently per block shape), which is why
     ``run_grid`` — whose contract is bitwise device-count independence —
     computes metrics on the gathered states instead."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.parallel import fleet as pfleet
 
     n_shards = mesh.shape[pfleet.SCENARIO_AXIS]
     b = pfleet.batch_size(final)
     padded, _mask = pfleet.pad_batch(final, n_shards)
     spec = pfleet.shard_spec()
-    fn = shard_map(jax.vmap(metrics), mesh=mesh, in_specs=(spec,),
-                   out_specs=spec, check_rep=False)
+    fn = jax.shard_map(jax.vmap(metrics), mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     return pfleet.unpad(fn(padded), b)
 
 

@@ -70,7 +70,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.core import asa
 from repro.core.bins import make_bins
@@ -677,12 +676,13 @@ def _sharded_sweep_fn(mesh, n_steps, chunk_steps, bf_passes, freed_mode,
                      rl_mode=rl_mode, faults=faults)
 
     if with_params:
-        fn = shard_map(block, mesh=mesh,
-                       in_specs=(spec, pfleet.replicated_spec()),
-                       out_specs=spec, check_rep=False)
+        fn = jax.shard_map(block, mesh=mesh,
+                           in_specs=(spec, pfleet.replicated_spec()),
+                           out_specs=spec, check_vma=False)
     else:
-        fn = shard_map(lambda shard: block(shard, None), mesh=mesh,
-                       in_specs=(spec,), out_specs=spec, check_rep=False)
+        fn = jax.shard_map(lambda shard: block(shard, None), mesh=mesh,
+                           in_specs=(spec,), out_specs=spec,
+                           check_vma=False)
     return jax.jit(fn)
 
 

@@ -414,6 +414,18 @@ def make_grid(cfg: XSimConfig,
     )
 
 
+def initial_states(grid: ScenarioGrid, fleet=None,
+                   pred_seed: int = 1) -> ScenarioState:
+    """The batched input tables ``run_grid`` sweeps: each scenario with
+    its geometry's slice of ``fleet`` (a fresh fleet when None) as its
+    live estimator, PRNG-decorrelated by ``pred_seed``."""
+    if fleet is None:
+        fleet = policies.init_fleet(int(grid.geo_idx.max()) + 1)
+    ests = policies.scenario_estimators(
+        fleet, jnp.asarray(grid.geo_idx), pred_seed)
+    return grid.build(ests)
+
+
 def run_grid(grid: ScenarioGrid, fleet=None, *, pred_seed: int = 1,
              bf_passes: int = backfill.BF_PASSES,
              freed_mode: str = "ref", params=None,
@@ -453,11 +465,7 @@ def run_grid(grid: ScenarioGrid, fleet=None, *, pred_seed: int = 1,
     if mesh is None and n_shards is not None:
         from repro.launch.mesh import make_scenarios_mesh
         mesh = make_scenarios_mesh(n_shards)
-    if fleet is None:
-        fleet = policies.init_fleet(int(grid.geo_idx.max()) + 1)
-    ests = policies.scenario_estimators(
-        fleet, jnp.asarray(grid.geo_idx), pred_seed)
-    states = grid.build(ests)
+    states = initial_states(grid, fleet, pred_seed)
     # RL shares ASA-Naive's no-dependency world (cancel/resubmit machinery)
     has_naive = bool(np.any((pols == ASA_NAIVE) | (pols == RL)))
     kw = dict(n_steps=grid.cfg.n_steps, chunk_steps=grid.cfg.chunk_steps,

@@ -109,3 +109,28 @@ def test_batched_estimators_independent():
     maps = jax.vmap(lambda st: asa.map_wait(st, bins))(s)
     est = np.asarray(maps)
     assert est[0] < est[1] < est[2]
+
+
+@pytest.mark.parametrize("waits", [(500.0, 2000.0), (2000.0, 500.0),
+                                   (30.0, 40.0), (40.0, 30.0)])
+def test_map_is_plain_argmax_on_near_ties(waits):
+    """Two observations in different bins leave those bins tied, 1 nat
+    above the rest; the f32 update leaves them an ULP or two apart, the
+    order of the observations deciding which is larger. The MAP is the
+    posterior's argmax all the same: no tie rule, whichever bin rounding
+    put on top, and never the bin a whole nat below."""
+    bins = jnp.asarray(make_bins(53), jnp.float32)
+    s = asa.init(53, jax.random.PRNGKey(0))
+    for w in waits:
+        s = asa.observe_full(s, zero_one(bins, jnp.float32(w)),
+                             jnp.float32(1.0 / 50), 50)
+    pair = sorted(nearest_bin(np.asarray(bins), waits).tolist())
+    log_p = np.asarray(s.log_p)
+    assert abs(log_p[pair[0]] - log_p[pair[1]]) < 1e-5
+    top = int(np.argmax(log_p))
+    assert top in pair
+    assert int(asa.greedy_action(s)) == top
+    assert float(asa.map_wait(s, bins)) == float(bins[top])
+    s1 = asa.observe_full(s, zero_one(bins, jnp.float32(max(waits))),
+                          jnp.float32(1.0 / 50), 50)
+    assert int(asa.greedy_action(s1)) == pair[1]

@@ -121,3 +121,29 @@ def test_campaign_overlaps_waits():
     # later-stage perceived waits must be far below the raw queue waits
     assert sum(pwts) < 0.5 * sum(waits[1:])
     assert rep.makespan_s > 0
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/cache"])
+def test_compile_cache_path(monkeypatch, env):
+    """Unset: the cache goes to one fixed directory inside the checkout.
+    Set: JAX keeps the variable's directory and nothing is set in code."""
+    from repro.runtime import compile_cache
+
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = compile_cache.enable()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env is None:
+        repo = compile_cache.REPO_CACHE_DIR.parent
+        assert path == after == str(repo / ".jax_cache")
+        assert (repo / ".gitignore").read_text().splitlines().count(
+            ".jax_cache/") == 1
+    else:
+        assert path == env
+        assert after == before
