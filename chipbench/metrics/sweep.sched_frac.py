@@ -1,0 +1,11 @@
+"""Share of the traced call's device-busy time spent in the scheduling
+pass: self time of the operations under the program's ``xsim.schedule``
+scope, its reservation (``xsim.reserve``) included, over the self time
+of every operation (``chipbench/scopes.py``)."""
+
+from chipbench import scopes
+
+
+def read(ctx):
+    s = scopes.of_run(ctx, "sweep_calls")
+    return None if s is None else s.frac("xsim.schedule", "xsim.reserve")

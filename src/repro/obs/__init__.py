@@ -5,7 +5,7 @@
 * ``obs.metrics`` — counters/histograms registry with vmap- and
   shard_map-aware fleet reductions.
 * ``obs.export`` — host-side decoding to Chrome trace-event JSON /
-  JSONL, schema validation, ``jax.profiler`` wiring.
+  JSONL, schema validation.
 * ``obs.telemetry`` — the unified (stdlib-only) telemetry schema all
   bench runners emit and ``bench_gate`` consumes.
 

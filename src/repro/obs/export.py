@@ -12,17 +12,12 @@ Perfetto / ``chrome://tracing``.
 ``jsonl_events`` is the structured-log view: one JSON object per decoded
 event, ready for ad-hoc ``jq``/pandas work.
 
-``profile_session`` wraps ``jax.profiler.start_trace``/``stop_trace``
-(compile-vs-steady attribution: annotate the first rep with
-``annotate("compile")`` and the rest with ``annotate("steady")``).
-
 Run ``python -m repro.obs.export --validate f.json ...`` to check a
 Chrome trace or telemetry file against its schema (CI's trace-smoke leg).
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 from typing import Any
 
@@ -230,31 +225,6 @@ def write_jsonl(path: str, final, labels=None) -> int:
         for r in rows:
             f.write(json.dumps(r) + "\n")
     return len(rows)
-
-
-# ------------------------------------------------- jax.profiler attribution
-
-
-@contextlib.contextmanager
-def profile_session(logdir: str | None):
-    """``jax.profiler`` start/stop around a bench section (None = off)."""
-    if logdir is None:
-        yield
-        return
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named profiler span (e.g. "compile" for rep 0, "steady" after)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 # ------------------------------------------------------- schema validation

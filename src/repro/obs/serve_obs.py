@@ -34,7 +34,11 @@ eviction races included — and ``requests_total`` always equals
 Time base: spans are wall-clock (``time.perf_counter`` relative to the
 ``ServeObs`` epoch), while device rings are *simulated* seconds — the
 merged trace interleaves the two clocks as separate pid rows, it does
-not align them.
+not align them. With spans on, each loop phase (:meth:`ServeObs.phase`)
+is also written into the ``jax.profiler`` trace as an
+``asa.serve.<phase>`` annotation on the serve thread, on the profiler's
+own clock, so a profile lines the loop's phases up with the device's
+operations.
 """
 
 from __future__ import annotations
@@ -58,9 +62,12 @@ TID_ADMISSION = 1   # admit/evict/table_full instants
 
 _US = 1_000_000.0
 
-# the batch-phase span names, in hot-path order (docs + tests key on it)
+# the loop-phase span names: the batch phases in hot-path order, the
+# checkpoint stall, and the idle wait for work (docs + tests key on it)
 PHASES = ("batch_form", "pad", "device_step", "scatter_read",
-          "future_resolve", "checkpoint_stall")
+          "future_resolve", "checkpoint_stall", "idle")
+# prefix of the phases' annotations in the jax.profiler trace
+ANNOTATION_PREFIX = "asa.serve."
 
 
 def serve_registry() -> Registry:
@@ -114,12 +121,52 @@ def serve_registry() -> Registry:
     h("asa_serve_request_latency_seconds", LATENCY_BUCKETS_S,
       "submit() to future resolution")
     h("asa_serve_device_step_seconds", LATENCY_BUCKETS_S,
-      "jitted serve_step dispatch (async — excludes host-blocked wait)")
+      "jitted serve_step dispatch only (async: the device's work is "
+      "waited for in the scatter read)")
     h("asa_serve_scatter_read_seconds", LATENCY_BUCKETS_S,
       "host-blocked device->host decision read")
     h("asa_serve_batch_fill", FRACTION_BUCKETS,
       "live rows / batch_size per dispatched batch")
     return r
+
+
+class _Phase:
+    """One loop phase with spans on: an annotation in the profiler's
+    trace on the calling thread, and its ``time.perf_counter`` start and
+    end (``t0``, ``t1``) inside it."""
+
+    __slots__ = ("_annotation", "t0", "t1")
+
+    def __init__(self, name: str):
+        import jax.profiler
+
+        self._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + name)
+
+    def __enter__(self) -> "_Phase":
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+
+
+class _NoPhase:
+    """The phase with spans off: no annotation, no clock read."""
+
+    __slots__ = ()
+    t0 = t1 = 0.0
+
+    def __enter__(self) -> "_NoPhase":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NO_PHASE = _NoPhase()
 
 
 class ServeObs:
@@ -189,6 +236,13 @@ class ServeObs:
     def now(self) -> float:
         """Wall-clock mark; 0.0 when spans are off (no syscall paid)."""
         return time.perf_counter() if self.spans else 0.0
+
+    def phase(self, name: str):
+        """Context timing one loop phase (a name in ``PHASES``): with
+        spans on, an ``asa.serve.<name>`` profiler annotation whose
+        ``t0``/``t1`` feed :meth:`span`; with spans off, a shared no-op
+        with ``t0 == t1 == 0.0``."""
+        return _Phase(name) if self.spans else _NO_PHASE
 
     def next_rid(self) -> int:
         """Monotone request id (itertools.count: GIL-atomic)."""

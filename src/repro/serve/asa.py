@@ -103,27 +103,29 @@ def _update_body(table: asa.ASAState, q: QueryBatch, mask: jax.Array,
     ``scatter_rows`` post-processes the locally-updated rows before the
     scatter — the sharded path all-gathers them so every device applies
     the identical full-batch write; the vmap path scatters them as-is.
+    Its device work is named ``asa.update`` for the profiler.
     """
     m = table.log_p.shape[-1]
     n = table.log_p.shape[0]
     bins = jnp.asarray(make_bins(m), jnp.float32)
-    slot = jnp.clip(q.slot, 0, n - 1)
+    with jax.named_scope("asa.update"):
+        slot = jnp.clip(q.slot, 0, n - 1)
 
-    # observations: gather each query's row, apply the tuned §4.5
-    # update where the query carries one (learn_wait_if is a no-op —
-    # PRNG included — on the False branch)
-    rows = jax.tree.map(lambda x: x[slot], table)
-    do = mask & q.has_obs
-    upd = jax.vmap(asa.learn_wait_if, in_axes=(0, None, 0, 0))(
-        rows, bins, q.observed_wait, do)
+        # observations: gather each query's row, apply the tuned §4.5
+        # update where the query carries one (learn_wait_if is a no-op —
+        # PRNG included — on the False branch)
+        rows = jax.tree.map(lambda x: x[slot], table)
+        do = mask & q.has_obs
+        upd = jax.vmap(asa.learn_wait_if, in_axes=(0, None, 0, 0))(
+            rows, bins, q.observed_wait, do)
 
-    # scatter the updated rows back; non-observing rows target index n
-    # (mode="drop"), so only real observations touch the table
-    tgt = jnp.where(do, slot, n)
-    if scatter_rows is not None:
-        tgt, upd = scatter_rows(tgt, upd)
-    return jax.tree.map(
-        lambda t, u: t.at[tgt].set(u, mode="drop"), table, upd)
+        # scatter the updated rows back; non-observing rows target index
+        # n (mode="drop"), so only real observations touch the table
+        tgt = jnp.where(do, slot, n)
+        if scatter_rows is not None:
+            tgt, upd = scatter_rows(tgt, upd)
+        return jax.tree.map(
+            lambda t, u: t.at[tgt].set(u, mode="drop"), table, upd)
 
 
 _apply_updates = jax.jit(_update_body)
@@ -138,16 +140,19 @@ def _read_decisions(table: asa.ASAState, q: QueryBatch) -> DecisionBatch:
     XLA may vectorize the same reduction differently at different batch
     widths (a 1-ULP wiggle) — running the one full-batch program on the
     replicated table makes the sharded decisions bit-identical to the
-    single-device ones by construction, not by luck.
+    single-device ones by construction, not by luck. Its device work is
+    named ``asa.read`` for the profiler.
     """
     m = table.log_p.shape[-1]
     n = table.log_p.shape[0]
     bins = jnp.asarray(make_bins(m), jnp.float32)
-    slot = jnp.clip(q.slot, 0, n - 1)
-    fresh = jax.tree.map(lambda x: x[slot], table)
-    feats = jax.vmap(asa.posterior_features, in_axes=(0, None))(fresh, bins)
-    return DecisionBatch(
-        lead_s=feats[:, 0], expected_s=feats[:, 1], entropy=feats[:, 2])
+    with jax.named_scope("asa.read"):
+        slot = jnp.clip(q.slot, 0, n - 1)
+        fresh = jax.tree.map(lambda x: x[slot], table)
+        feats = jax.vmap(asa.posterior_features, in_axes=(0, None))(
+            fresh, bins)
+        return DecisionBatch(
+            lead_s=feats[:, 0], expected_s=feats[:, 1], entropy=feats[:, 2])
 
 
 def decision_step(table: asa.ASAState, q: QueryBatch, mask: jax.Array

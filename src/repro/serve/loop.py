@@ -402,21 +402,38 @@ class ASAServer:
             self._queue.put((req, fut))
         return fut
 
-    def _drain(self, wait_s: float) -> list[tuple[Request, Future]]:
+    def _await_work(self, wait_s: float) -> None:
+        """With nothing deferred, take the first queued request into the
+        deferred deque, waiting up to ``wait_s`` for one; the time
+        blocked with nothing queued is the ``idle`` phase."""
+        pending = self._deferred
+        if pending:
+            return
+        try:
+            pending.append(self._queue.get_nowait())
+            return
+        except queue.Empty:
+            if wait_s <= 0:
+                return
+        o = self._obs
+        with o.phase("idle") as idle:
+            try:
+                pending.append(self._queue.get(timeout=wait_s))
+            except queue.Empty:
+                pass
+        o.span("idle", idle.t0, idle.t1)
+
+    def _drain(self) -> list[tuple[Request, Future]]:
         """Pull queued requests into the deferred deque, then pick the
         next batch in order — shedding expired-deadline requests, and
         deferring any tenant whose second same-batch observation would
         break the unique-scatter invariant."""
         pending = self._deferred
-        timeout = wait_s if not pending else 0.0
         while True:
             try:
-                item = (self._queue.get(timeout=timeout)
-                        if timeout > 0 else self._queue.get_nowait())
+                pending.append(self._queue.get_nowait())
             except queue.Empty:
                 break
-            pending.append(item)
-            timeout = 0.0
         batch: list[tuple[Request, Future]] = []
         held: deque[tuple[Request, Future]] = deque()
         obs_seen: set[int] = set()
@@ -457,28 +474,11 @@ class ASAServer:
         o.g_deferred.set(len(held))
         return batch
 
-    def step_once(self, wait_s: Optional[float] = None) -> int:
-        """Drain + dispatch one batch; returns the number of requests
-        answered (0 when the queue stayed empty).
-
-        Containment contract: everything from batch-form to the host
-        decision read runs under a per-batch guard — a failure there
-        resolves this batch's futures with
-        :class:`repro.serve.asa.ServeStepError` and returns; the table
-        keeps its pre-dispatch state (the functional update commits only
-        after the host read), and the loop lives on.  Only an exception
-        *outside* the guard (e.g. an injected crash at the boundary)
-        kills the loop — and then the crash path drains everything."""
-        if self._chaos is not None:
-            # boundary hook: bursts land in the queue (drained below, or
-            # by the crash path), a crash raise escapes to _run
-            self._chaos.on_batch_boundary(self)
+    def _admit_batch(self, batch: list[tuple[Request, Future]]):
+        """Map a drained batch onto table slots, admitting new tenants;
+        returns ``(live, slots, waits, has)`` with ``live`` the rows
+        that got a slot, as ``(row, future, request)``."""
         o = self._obs
-        t0 = o.now()
-        batch = self._drain(self.cfg.batch_wait_s
-                            if wait_s is None else wait_s)
-        if not batch:
-            return 0
         # tenants with rows in THIS batch must survive pressure eviction:
         # a shed-then-readmit inside one batch would reuse a slot within
         # a single scatter
@@ -516,27 +516,55 @@ class ASAServer:
             self._requests_of[req.tenant] = \
                 self._requests_of.get(req.tenant, 0) + 1
             live.append((i, fut, req))
-        if not live:  # every request failed admission — nothing to serve
+        return live, slots, waits, has
+
+    def step_once(self, wait_s: Optional[float] = None) -> int:
+        """Drain + dispatch one batch; returns the number of requests
+        answered (0 when the queue stayed empty).
+
+        Containment contract: everything from batch-form to the host
+        decision read runs under a per-batch guard — a failure there
+        resolves this batch's futures with
+        :class:`repro.serve.asa.ServeStepError` and returns; the table
+        keeps its pre-dispatch state (the functional update commits only
+        after the host read), and the loop lives on.  Only an exception
+        *outside* the guard (e.g. an injected crash at the boundary)
+        kills the loop — and then the crash path drains everything."""
+        if self._chaos is not None:
+            # boundary hook: bursts land in the queue (drained below, or
+            # by the crash path), a crash raise escapes to _run
+            self._chaos.on_batch_boundary(self)
+        o = self._obs
+        self._await_work(self.cfg.batch_wait_s
+                         if wait_s is None else wait_s)
+        if not self._deferred:
+            return 0
+        # the batch forms from the first request in hand: the wait for
+        # it was the idle phase
+        with o.phase("batch_form") as form:
+            batch = self._drain()
+            live, slots, waits, has = self._admit_batch(batch)
+        if not live:  # nothing to serve: all shed or refused admission
             return 0
         try:
             if self._chaos is not None:
                 self._chaos.before_device_step(self._batches)
-            t1 = o.now()
-            q = serve_asa.QueryBatch(
-                slot=jax.numpy.asarray(slots),
-                observed_wait=jax.numpy.asarray(waits),
-                has_obs=jax.numpy.asarray(has))
-            # pad to the one compiled (batch_size,) shape; the mask
-            # guards the pad rows (copies of query 0) from ever touching
-            # the table
-            qp, mask = pfleet.pad_batch(q, self.cfg.batch_size)
-            t2 = o.now()
-            new_table, dec = serve_asa.serve_step(self._table, qp, mask,
-                                                  mesh=self._mesh)
-            t3 = o.now()
+            with o.phase("pad") as pad:
+                q = serve_asa.QueryBatch(
+                    slot=jax.numpy.asarray(slots),
+                    observed_wait=jax.numpy.asarray(waits),
+                    has_obs=jax.numpy.asarray(has))
+                # pad to the one compiled (batch_size,) shape; the mask
+                # guards the pad rows (copies of query 0) from ever
+                # touching the table
+                qp, mask = pfleet.pad_batch(q, self.cfg.batch_size)
+            with o.phase("device_step") as step:
+                new_table, dec = serve_asa.serve_step(
+                    self._table, qp, mask, mesh=self._mesh)
             # ONE host-blocked device read for the whole decision batch —
             # the scatter-read leg of the request lifecycle
-            lead, expected, entropy = serve_asa.decisions_to_host(dec)
+            with o.phase("scatter_read") as read:
+                lead, expected, entropy = serve_asa.decisions_to_host(dec)
         except Exception as e:
             # per-batch containment: this batch's futures fail typed,
             # the table keeps its pre-dispatch state, the loop survives
@@ -554,38 +582,40 @@ class ASAServer:
                       {"batch": self._batches, "error": repr(e)})
             return 0
         self._table = new_table   # commit only after the read succeeded
-        t4 = o.now()
-        # one resolve timestamp + one bulk resolve for the whole batch —
-        # the requests leave together, and per-request observability
-        # calls are measurable at full rate (the bench's overhead
-        # budget pays for them)
-        t_res = o.now()
-        for i, fut, req in live:
-            fut.set_result(Decision(req.tenant, float(lead[i]),
-                                    float(expected[i]),
-                                    float(entropy[i])))
-        o.resolve_many([req for _i, _f, req in live], t_res)
-        self._batches += 1
-        self._last_batch_ts = time.monotonic()
-        o.c_batches.inc()
-        o.c_decisions.inc(len(live))
-        o.c_padded.inc(self.cfg.batch_size - len(live))
+        with o.phase("future_resolve") as resolve:
+            # one resolve timestamp + one bulk resolve for the whole
+            # batch — the requests leave together, and per-request
+            # observability calls are measurable at full rate (the
+            # bench's overhead budget pays for them)
+            t_res = o.now()
+            for i, fut, req in live:
+                fut.set_result(Decision(req.tenant, float(lead[i]),
+                                        float(expected[i]),
+                                        float(entropy[i])))
+            o.resolve_many([req for _i, _f, req in live], t_res)
+            self._batches += 1
+            self._last_batch_ts = time.monotonic()
+            o.c_batches.inc()
+            o.c_decisions.inc(len(live))
+            o.c_padded.inc(self.cfg.batch_size - len(live))
         if o.spans:
-            t5 = o.now()
             fill = len(live) / self.cfg.batch_size
             o.h_batch_fill.observe(fill)
-            o.h_device_step.observe(t3 - t2)
-            o.h_scatter_read.observe(t4 - t3)
-            o.span("batch_form", t0, t1, {
+            o.h_device_step.observe(step.t1 - step.t0)
+            o.h_scatter_read.observe(read.t1 - read.t0)
+            o.span("batch_form", form.t0, form.t1, {
                 "batch": self._batches, "size": len(batch),
                 "live": len(live), "batch_size": self.cfg.batch_size,
                 "n_obs": int(has.sum()),
                 "pad_fraction": 1.0 - fill,
                 "deferred": len(self._deferred)})
-            o.span("pad", t1, t2)
-            o.span("device_step", t2, t3, {"async_dispatch": True})
-            o.span("scatter_read", t3, t4, {"host_blocked": True})
-            o.span("future_resolve", t4, t5, {"resolved": len(live)})
+            o.span("pad", pad.t0, pad.t1)
+            o.span("device_step", step.t0, step.t1,
+                   {"async_dispatch": True})
+            o.span("scatter_read", read.t0, read.t1,
+                   {"host_blocked": True})
+            o.span("future_resolve", resolve.t0, resolve.t1,
+                   {"resolved": len(live)})
         if (self.cfg.checkpoint_every
                 and self._batches % self.cfg.checkpoint_every == 0):
             # cadenced saves are contained: a failed snapshot (or a
@@ -655,7 +685,9 @@ class ASAServer:
                     # queue stayed empty for batch_wait_s: yield briefly
                     # so a stopped server exits promptly (sqswatcher's
                     # idle poll)
-                    self._stop.wait(self.cfg.batch_wait_s)
+                    with o.phase("idle") as idle:
+                        self._stop.wait(self.cfg.batch_wait_s)
+                    o.span("idle", idle.t0, idle.t1)
             o.g_loop_healthy.set(0.0)
         except BaseException as e:
             self._crash(e)
@@ -810,9 +842,10 @@ class ASAServer:
         assert self.cfg.checkpoint_dir, "ServeConfig.checkpoint_dir unset"
         o = self._obs
         if self._ckpt_handle is not None:
-            ts = time.perf_counter()
-            self._ckpt_handle.result()
-            stall = time.perf_counter() - ts
+            with o.phase("checkpoint_stall"):
+                ts = time.perf_counter()
+                self._ckpt_handle.result()
+                stall = time.perf_counter() - ts
             o.c_ckpt_stall_s.inc(stall)
             if o.spans:
                 o.span("checkpoint_stall", ts, ts + stall,

@@ -232,46 +232,54 @@ def _start_rows(s: ScenarioState, mask: jax.Array, now) -> ScenarioState:
 
 def schedule_pass(s: ScenarioState, *, bf_passes: int = BF_PASSES,
                   freed_mode: str = "ref") -> ScenarioState:
-    """One FCFS + EASY-backfill pass at the current sim time ``s.t``."""
-    now = s.t
-    n = s.status.shape[0]
+    """One FCFS + EASY-backfill pass at the current sim time ``s.t``.
 
-    # 1. maximal FCFS prefix that fits ------------------------------------
-    elig = eligible_mask(s)
-    order, rank = fcfs_order(s, elig)
-    sorted_elig = elig[order]
-    sorted_cores = jnp.where(sorted_elig, s.cores[order], 0.0)
-    csum = jnp.cumsum(sorted_cores)
-    fits = sorted_elig & (csum <= s.free)
-    # cores > 0 ⇒ csum monotone ⇒ `fits` is automatically a prefix
-    start_mask = jnp.zeros(n, bool).at[order].set(fits)
-    s = _start_rows(s, start_mask, now)
+    Its device work is named ``xsim.schedule`` for the profiler, and the
+    reservation's ``xsim.reserve`` inside it, whatever ``freed_mode``
+    computes it."""
+    with jax.named_scope("xsim.schedule"):
+        now = s.t
+        n = s.status.shape[0]
 
-    # 2. reservation for the head (first eligible job that did not fit) ---
-    elig = eligible_mask(s)
-    n_elig = jnp.sum(elig)
-    head = jnp.argmin(jnp.where(elig, rank, n))   # FCFS-first leftover
-    has_head = n_elig > 0
-    running = s.status == RUNNING
-    freed = freed_vector(s.end, s.cores, running, mode=freed_mode)
-    shadow, extra = reservation(
-        s.end, s.cores, running, s.free,
-        jnp.where(has_head, s.cores[head], 0.0), freed=freed)
-
-    # 3. bounded backfill loop -------------------------------------------
-    def body(_, carry):
-        s, extra = carry
+        # 1. maximal FCFS prefix that fits --------------------------------
         elig = eligible_mask(s)
-        cand = (elig & (jnp.arange(n) != head) & (s.cores <= s.free)
-                & ((now + s.duration <= shadow) | (s.cores <= extra)))
-        pick = jnp.argmin(jnp.where(cand, rank, n))
-        do = jnp.any(cand) & has_head
-        pick_mask = (jnp.arange(n) == pick) & do
-        # QueueSim decrements the reservation's spare only when the job
-        # rode in on it (fits_in_extra), even if it also drains in time
-        used_extra = jnp.where(do & (s.cores[pick] <= extra),
-                               s.cores[pick], 0.0)
-        return _start_rows(s, pick_mask, now), extra - used_extra
+        order, rank = fcfs_order(s, elig)
+        sorted_elig = elig[order]
+        sorted_cores = jnp.where(sorted_elig, s.cores[order], 0.0)
+        csum = jnp.cumsum(sorted_cores)
+        fits = sorted_elig & (csum <= s.free)
+        # cores > 0 ⇒ csum monotone ⇒ `fits` is automatically a prefix
+        start_mask = jnp.zeros(n, bool).at[order].set(fits)
+        s = _start_rows(s, start_mask, now)
 
-    s, _ = jax.lax.fori_loop(0, bf_passes, body, (s, extra))
-    return s
+        # 2. reservation for the head (first eligible job that did not
+        # fit) ----------------------------------------------------------
+        elig = eligible_mask(s)
+        n_elig = jnp.sum(elig)
+        head = jnp.argmin(jnp.where(elig, rank, n))   # FCFS-first leftover
+        has_head = n_elig > 0
+        running = s.status == RUNNING
+        with jax.named_scope("xsim.reserve"):
+            freed = freed_vector(s.end, s.cores, running, mode=freed_mode)
+            shadow, extra = reservation(
+                s.end, s.cores, running, s.free,
+                jnp.where(has_head, s.cores[head], 0.0), freed=freed)
+
+        # 3. bounded backfill loop ---------------------------------------
+        def body(_, carry):
+            s, extra = carry
+            elig = eligible_mask(s)
+            cand = (elig & (jnp.arange(n) != head) & (s.cores <= s.free)
+                    & ((now + s.duration <= shadow) | (s.cores <= extra)))
+            pick = jnp.argmin(jnp.where(cand, rank, n))
+            do = jnp.any(cand) & has_head
+            pick_mask = (jnp.arange(n) == pick) & do
+            # QueueSim decrements the reservation's spare only when the
+            # job rode in on it (fits_in_extra), even if it also drains in
+            # time
+            used_extra = jnp.where(do & (s.cores[pick] <= extra),
+                                   s.cores[pick], 0.0)
+            return _start_rows(s, pick_mask, now), extra - used_extra
+
+        s, _ = jax.lax.fori_loop(0, bf_passes, body, (s, extra))
+        return s

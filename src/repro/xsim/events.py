@@ -111,19 +111,22 @@ def next_event_time(s: ScenarioState, naive: bool = True,
     CANCELLED rows with a finite submit are naive resubmissions waiting
     for their corrected time; ``repass`` pins the next step to the current
     instant (mid-event estimator/cancel cascades). ``faults=False``
-    (static) elides the fault-schedule term entirely."""
-    submittable = s.status == PENDING
-    if naive:
-        submittable |= s.status == CANCELLED
-    submits = jnp.where(submittable, s.submit, jnp.inf)
-    ends = jnp.where(s.status == RUNNING, s.end, jnp.inf)
-    nxt = jnp.minimum(jnp.min(submits), jnp.min(ends))
-    if faults and s.fault_t.shape[0]:
-        nf = s.fault_t.shape[0]
-        i = jnp.clip(s.fault_next, 0, nf - 1)
-        ft = jnp.where(s.fault_next < nf, s.fault_t[i], jnp.inf)
-        nxt = jnp.minimum(nxt, ft)
-    return jnp.where(s.repass, s.t, nxt)
+    (static) elides the fault-schedule term entirely. Its device work is
+    named ``xsim.events`` wherever it runs, ``simulate``'s drain check
+    included."""
+    with jax.named_scope("xsim.events"):
+        submittable = s.status == PENDING
+        if naive:
+            submittable |= s.status == CANCELLED
+        submits = jnp.where(submittable, s.submit, jnp.inf)
+        ends = jnp.where(s.status == RUNNING, s.end, jnp.inf)
+        nxt = jnp.minimum(jnp.min(submits), jnp.min(ends))
+        if faults and s.fault_t.shape[0]:
+            nf = s.fault_t.shape[0]
+            i = jnp.clip(s.fault_next, 0, nf - 1)
+            ft = jnp.where(s.fault_next < nf, s.fault_t[i], jnp.inf)
+            nxt = jnp.minimum(nxt, ft)
+        return jnp.where(s.repass, s.t, nxt)
 
 
 def complete_jobs(s: ScenarioState, now, faults: bool = False
@@ -495,7 +498,8 @@ def _drain_hooks(s: ScenarioState, now, bins, greedy, naive: bool,
         return _chain_hook(s, now, bins, greedy, params, rl_mode)
         # … then predict, as the event-driven sim does
 
-    return jax.lax.while_loop(cond, body, s)
+    with jax.named_scope("xsim.hooks"):
+        return jax.lax.while_loop(cond, body, s)
 
 
 def sim_step(s: ScenarioState, bins, *, bf_passes: int = backfill.BF_PASSES,
@@ -513,39 +517,52 @@ def sim_step(s: ScenarioState, bins, *, bf_passes: int = backfill.BF_PASSES,
     ``_chain_hook``); ``params=None`` elides it. ``faults=False`` asserts
     (statically) that no scenario carries capacity-fault events, eliding
     the fault machinery (``_apply_faults`` + drain-debt collection) —
-    ``grid.run_grid`` sets it from the grid's fault schedules."""
+    ``grid.run_grid`` sets it from the grid's fault schedules.
+
+    The device work is named by phase for the profiler (op metadata
+    only; the compiled program is the same): ``xsim.events`` (time
+    advance, completions, releases, faults, admissions),
+    ``xsim.schedule`` (the scheduling pass, its reservation under
+    ``xsim.reserve``) and ``xsim.hooks`` (the ASA start and chain
+    hooks)."""
     if rl_mode not in ("sample", "greedy"):
         raise ValueError(f"unknown rl_mode {rl_mode!r}")
     greedy = {None: s.pred_greedy, "greedy": True,
               "sample": False}[pred_mode]
-    nxt = next_event_time(s, naive, faults)
-    now = jnp.where(jnp.isfinite(nxt), jnp.maximum(nxt, s.t), s.t)
-    # utilization integral over (t, now] at the pre-event allocation
-    busy_cs = s.busy_cs + (s.total - s.free) * (now - s.t)
-    s = s._replace(t=now, busy_cs=busy_cs, repass=jnp.asarray(False),
-                   # drained lanes don't count: `steps` is the
-                   # events-executed profile signal vs. the n_steps budget
-                   steps=s.steps + jnp.isfinite(nxt).astype(jnp.int32))
-    s, newly_done = complete_jobs(s, now, faults)
-    s = _release_per_stage(s, newly_done, now)
-    resub_fire = resub_succ = None
-    if naive:
-        s, resub_fire, resub_succ = _release_naive_resubmit(
-            s, newly_done, now)
-    if faults:
-        # after completions (a job ending at the fault instant finished),
-        # before admissions/scheduling (which see post-fault capacity)
-        s = _apply_faults(s, now)
-    s, newly_admitted = admit_jobs(s, now, naive)
-    # first admissions of ASA/naive stages queue a chain-hook event
-    # (the -inf expected_end sentinel keeps resubmissions from re-firing)
-    rows = jnp.clip(s.wf_rows, 0, s.status.shape[0] - 1)
-    stage_ok = (s.wf_rows >= 0) & _asa_like(s)
-    s = s._replace(chain_pending=s.chain_pending | (
-        stage_ok & newly_admitted[rows] & jnp.isneginf(s.expected_end[rows])))
+    with jax.named_scope("xsim.events"):
+        nxt = next_event_time(s, naive, faults)
+        now = jnp.where(jnp.isfinite(nxt), jnp.maximum(nxt, s.t), s.t)
+        # utilization integral over (t, now] at the pre-event allocation
+        busy_cs = s.busy_cs + (s.total - s.free) * (now - s.t)
+        s = s._replace(t=now, busy_cs=busy_cs, repass=jnp.asarray(False),
+                       # drained lanes don't count: `steps` is the
+                       # events-executed profile signal vs. the n_steps
+                       # budget
+                       steps=s.steps + jnp.isfinite(nxt).astype(jnp.int32))
+        s, newly_done = complete_jobs(s, now, faults)
+        s = _release_per_stage(s, newly_done, now)
+        resub_fire = resub_succ = None
+        if naive:
+            s, resub_fire, resub_succ = _release_naive_resubmit(
+                s, newly_done, now)
+        if faults:
+            # after completions (a job ending at the fault instant
+            # finished), before admissions/scheduling (which see
+            # post-fault capacity)
+            s = _apply_faults(s, now)
+        s, newly_admitted = admit_jobs(s, now, naive)
+        # first admissions of ASA/naive stages queue a chain-hook event
+        # (the -inf expected_end sentinel keeps resubmissions from
+        # re-firing)
+        rows = jnp.clip(s.wf_rows, 0, s.status.shape[0] - 1)
+        stage_ok = (s.wf_rows >= 0) & _asa_like(s)
+        s = s._replace(chain_pending=s.chain_pending | (
+            stage_ok & newly_admitted[rows]
+            & jnp.isneginf(s.expected_end[rows])))
     pre_start = s.start
     s = backfill.schedule_pass(s, bf_passes=bf_passes, freed_mode=freed_mode)
-    started = (s.status == RUNNING) & jnp.isinf(pre_start)
+    with jax.named_scope("xsim.hooks"):
+        started = (s.status == RUNNING) & jnp.isinf(pre_start)
     if s.trace is not None:
         # one fused ring write per step, in event order: finishes,
         # naive resubmissions, admissions, starts (cancels are appended
@@ -562,8 +579,9 @@ def sim_step(s: ScenarioState, bins, *, bf_passes: int = backfill.BF_PASSES,
         segs.append((started, obs_trace.EV_START, row_i, stg, s.cores))
         s = s._replace(trace=obs_trace.append_segments(
             s.trace, segs, t=now, policy=s.policy, step=s.steps))
-    s = s._replace(start_pending=s.start_pending | (
-        stage_ok & started[rows]))
+    with jax.named_scope("xsim.hooks"):
+        s = s._replace(start_pending=s.start_pending | (
+            stage_ok & started[rows]))
     return _drain_hooks(s, now, bins, greedy, naive, params, rl_mode)
 
 

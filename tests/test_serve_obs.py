@@ -250,20 +250,39 @@ def test_deferred_duplicates_conserve_and_count():
 # ------------------------------------------------- bit-identity + stats
 
 
-def test_decisions_bit_identical_spans_on_off():
+def _profiler_names(logdir) -> set[str]:
+    from jax.profiler import ProfileData
+
+    path = next(logdir.rglob("*.xplane.pb"))
+    return {e.name for p in ProfileData.from_file(str(path)).planes
+            for ln in p.lines for e in ln.events}
+
+
+def test_decisions_bit_identical_spans_on_off(tmp_path):
     """The acceptance bar: the registry-off default path answers bitwise
     what the fully-instrumented server answers — observability is host
-    bookkeeping only, it never touches device numerics."""
+    bookkeeping only, it never touches device numerics. Each server runs
+    under the profiler: spans off write no ``asa.serve.*``
+    annotation into its trace, spans on write every batch phase."""
+    import jax
+
     traffic = [(t % 4, 60.0 * (1 + t % 5)) for t in range(16)]
     answers = []
     for spans in (False, True):
         server = ASAServer(_cfg(obs_spans=spans))
-        futs = [server.submit(t, observed_wait=w) for t, w in traffic]
-        _drain_all(server, futs)
+        logdir = tmp_path / f"spans_{spans}"
+        with jax.profiler.trace(str(logdir)):
+            futs = [server.submit(t, observed_wait=w) for t, w in traffic]
+            _drain_all(server, futs)
         answers.append([(d.lead_s, d.expected_s, d.entropy)
                         for d in (f.result(timeout=10) for f in futs)])
-        if not spans:
+        written = {n for n in _profiler_names(logdir)
+                   if n.startswith("asa.serve.")}
+        if spans:
+            assert {f"asa.serve.{p}" for p in PHASES[:5]} <= written
+        else:
             assert len(server.obs.events) == 0   # no spans recorded
+            assert not written
     assert answers[0] == answers[1]
 
 
