@@ -8,18 +8,23 @@ Mirrors ``QueueSim._schedule_pass`` with masked array ops:
      the free cores, so one sort + cumsum starts any number of head jobs.
   2. *Reservation* — when the queue head does not fit, compute its
      earliest feasible start (shadow time) and the spare cores at that
-     moment. The hot quantity is freed[i] = Σ cores of running jobs
-     ending ≤ end_i; the default path computes it in O(n log n) by
-     sorting the running jobs by end time, cumsum-ing their cores and
-     gathering the cumsum at the last index of each end-time tie run
-     (``_freed_sorted``). The original O(n²) pairwise comparison stays
-     available as ``freed_mode="ref_n2"`` for differential checks — the
-     two agree bit-for-bit on the integer-valued core counts every grid
-     uses (both sums are exact integers below 2**24). A Pallas kernel
-     (`freed_matrix`) runs the same sorted formulation batched on
-     accelerator: XLA sorts the (B, N) tables, the kernel does the O(n)
-     scan portion (cores cumsum + tie-aware backward fill) in VMEM, and
-     the result scatters back through the inverse permutation.
+     moment. The default path computes both scalars directly: one
+     key-value sort of the running jobs' (end, cores), a cores cumsum,
+     the smallest end at which free + cumsum covers the head (a min
+     reduction), and the cores of every job ending by then (a masked
+     sum) — no gather, no binary search. The other modes compute the
+     per-row vector freed[i] = Σ cores of running jobs ending ≤ end_i
+     and read the reservation from it, as differential checks: the
+     O(n²) pairwise comparison (``freed_mode="ref_n2"``) and a Pallas
+     kernel (`freed_matrix`, ``"tpu"``/``"interpret"``) that runs the
+     O(n log n) sorted formulation batched on the accelerator: XLA
+     sorts the (B, N) tables, the kernel does the O(n) scan portion
+     (cores cumsum + tie-aware backward fill) in VMEM, and the result
+     scatters back through the inverse permutation. Its jnp reference,
+     ``_freed_sorted``, gathers the cumsum at the last index of each
+     end-time tie run. All of them agree bit-for-bit on the
+     integer-valued core counts every grid uses (every sum is an exact
+     integer below 2**24).
   3. *Backfill loop* — a short `fori_loop`; each pass starts the first
      (FCFS order) queued job that fits now AND either drains before the
      shadow time or fits inside the reservation's spare cores. QueueSim
@@ -149,9 +154,9 @@ def freed_matrix(ends, cores, running, *, interpret: bool = False):
     single row ``freed_vector`` passes under ``jax.vmap``, is one block
     of its own height. Both block shapes meet Mosaic's rule that a
     block's last two dims divide by (8, 128) or equal the array's. Used
-    on TPU (or under ``interpret`` for tests); the sweep's default CPU
-    path inlines the jnp sorted reference, keeping `schedule_pass`
-    trivially vmap-able. Bit-identical to ``_freed_sorted`` (and to the
+    with ``freed_mode="tpu"`` (or ``"interpret"`` for tests); the
+    default path computes the reservation without the vector
+    (``reservation``). Bit-identical to ``_freed_sorted`` (and to the
     O(n²) reference on integer cores).
     """
     B, N = ends.shape
@@ -181,11 +186,13 @@ def freed_matrix(ends, cores, running, *, interpret: bool = False):
 def freed_vector(ends, cores, running, *, mode: str = "ref"):
     """Dispatch the freed-cores scan.
 
-    ``ref``: the sorted O(n log n) jnp path (the CPU default — trivially
-    vmap-able). ``ref_n2``: the original O(n²) pairwise reference, kept
-    for differential checks. ``interpret``/``tpu``: the sorted Pallas
-    kernel, run single-scenario; under ``jax.vmap`` the batching rule
-    gives it a grid of B one-row (1, N) blocks.
+    ``ref``: the sorted O(n log n) jnp path, the Pallas kernel's
+    bit-exact reference (``schedule_pass`` in this mode skips the vector
+    and computes the reservation directly). ``ref_n2``: the original
+    O(n²) pairwise reference, kept for differential checks.
+    ``interpret``/``tpu``: the sorted Pallas kernel, run single-scenario;
+    under ``jax.vmap`` the batching rule gives it a grid of B one-row
+    (1, N) blocks.
     """
     if mode == "ref":
         return _freed_sorted(ends, cores, running)
@@ -201,14 +208,27 @@ def freed_vector(ends, cores, running, *, mode: str = "ref"):
 def reservation(ends, cores, running, free, head_cores, freed=None):
     """EASY reservation: (shadow_time, spare_cores_at_shadow) for the head.
 
-    ``freed`` may be precomputed (e.g. by the Pallas kernel); otherwise
-    the sorted jnp path is used. Semantics match
-    ``QueueSim._reservation``: walk running jobs by end time until the
-    head fits; no feasible point → +inf.
+    Semantics match ``QueueSim._reservation``: walk running jobs by end
+    time until the head fits; no feasible point → (+inf, 0).
+
+    Without ``freed`` both scalars come straight from one key-value sort
+    of (end, cores): the cores cumsum at sorted position k is what the
+    first k+1 enders release, so the shadow is the smallest finite end
+    whose cumsum covers the head (a tie run's last position holds the
+    run's full total, so ties need no care), and the spare cores are the
+    free cores plus those of every job ending by the shadow, less the
+    head's. A precomputed ``freed`` vector (the O(n²) reference or the
+    Pallas kernel) is read at the earliest feasible row instead. Both
+    are exact on integer core counts, whose sums stay below 2**24.
     """
-    if freed is None:
-        freed = _freed_sorted(ends, cores, running)
     e = jnp.where(running, ends, jnp.inf)
+    if freed is None:
+        c = jnp.where(running, cores, 0.0)
+        e_s, c_s = jax.lax.sort((e, c), num_keys=1)
+        hit = (free + jnp.cumsum(c_s) >= head_cores) & jnp.isfinite(e_s)
+        shadow = jnp.min(jnp.where(hit, e_s, jnp.inf))   # +inf: no hit
+        spare = free + jnp.sum(jnp.where(e <= shadow, c, 0.0)) - head_cores
+        return shadow, jnp.where(jnp.isfinite(shadow), spare, 0.0)
     ok = running & (free + freed >= head_cores)
     pick = jnp.argmin(jnp.where(ok, e, jnp.inf))
     any_ok = jnp.any(ok)
@@ -260,7 +280,8 @@ def schedule_pass(s: ScenarioState, *, bf_passes: int = BF_PASSES,
         has_head = n_elig > 0
         running = s.status == RUNNING
         with jax.named_scope("xsim.reserve"):
-            freed = freed_vector(s.end, s.cores, running, mode=freed_mode)
+            freed = (None if freed_mode == "ref" else
+                     freed_vector(s.end, s.cores, running, mode=freed_mode))
             shadow, extra = reservation(
                 s.end, s.cores, running, s.free,
                 jnp.where(has_head, s.cores[head], 0.0), freed=freed)

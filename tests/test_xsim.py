@@ -10,6 +10,8 @@ states on both sides and require the sampled prediction sequences to
 match action-for-action through the whole scenario.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -315,6 +317,27 @@ def test_freed_mode_ref_n2_end_to_end():
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     with pytest.raises(ValueError, match="freed mode"):
         events.simulate(st, n_steps=8, freed_mode="bogus")
+
+
+_HLO_OPCODE = re.compile(r"=\s*(?:\(.*?\)|\S+)\s+([a-z][a-z0-9-]*)\(")
+
+
+def test_default_reservation_compiles_without_gather_or_loop():
+    """On the default path the reservation is one sort, a cumsum and
+    reductions: no instruction the compiler keeps under ``xsim.reserve``
+    is a ``gather`` (an index read over the rows) or a ``while`` (the
+    binary search of ``searchsorted``)."""
+    t, kw = _bare()
+    for i in range(6):
+        add_job(t, i, cores=30, duration=100.0 * (i + 1), submit=0.0,
+                status=X.QUEUED)
+    st = freeze(t, **kw)
+    hlo = jax.jit(lambda s: backfill.schedule_pass(s, freed_mode="ref")
+                  ).lower(st).compile().as_text()
+    ops = {m.group(1) for line in hlo.splitlines() if "xsim.reserve" in line
+           for m in [_HLO_OPCODE.search(line)] if m}
+    assert "sort" in ops
+    assert not ops & {"gather", "while"}, sorted(ops)
 
 
 def test_pallas_freed_mode_end_to_end():

@@ -153,6 +153,85 @@ def test_sorted_freed_matches_n2_reference_exactly(seed, n, force_ties):
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(fast))
 
 
+def _both_reservations(ends, cores, running, free, head_cores):
+    """(shadow, extra) computed directly, and read from the O(n²)
+    reference's freed vector."""
+    direct = backfill.reservation(ends, cores, running, free, head_cores)
+    via_vector = backfill.reservation(
+        ends, cores, running, free, head_cores,
+        freed=backfill._freed_math(ends, cores, running))
+    return direct, via_vector
+
+
+# head cases: any width, wider than the whole machine, just what the
+# first end-time tie run frees, zero cores
+_HEAD_ANY, _HEAD_NEVER, _HEAD_FIRST, _HEAD_ZERO = range(4)
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.integers(0, 2**31 - 1), st.integers(1, 64), st.booleans(),
+       st.integers(0, 4), st.integers(0, 3))
+def test_direct_reservation_matches_freed_vector_exactly(
+        seed, n, force_ties, fill, head_case):
+    """The default reservation, computed from one sort of (end, cores)
+    with no freed vector, returns the same (shadow, extra) as reading
+    the O(n²) reference's vector, bit for bit: tie runs, tables with no
+    running row (``fill`` 0), a head that never fits (+inf, 0), one that
+    fits at the first end, and a head of 0 cores included."""
+    rng = np.random.default_rng(seed)
+    if force_ties:
+        ends = rng.choice([60.0, 600.0, 600.0, 3600.0, 86400.0], size=n)
+    else:
+        ends = rng.uniform(0.0, 1e5, n)
+    ends = ends.astype(np.float32)
+    cores = rng.integers(1, 512, n).astype(np.float32)
+    running = rng.random(n) < fill / 4
+    free = np.float32(rng.integers(0, 200))
+    if head_case == _HEAD_NEVER:
+        head = free + cores[running].sum() + 1
+    elif head_case == _HEAD_FIRST and running.any():
+        first = ends[running].min()
+        head = free + cores[running & (ends == first)].sum()
+    elif head_case == _HEAD_ZERO:
+        head = 0.0
+    else:
+        head = rng.integers(0, free + cores[running].sum() + 2)
+    direct, via_vector = _both_reservations(
+        jnp.asarray(ends), jnp.asarray(cores), jnp.asarray(running),
+        jnp.float32(free), jnp.float32(head))
+    np.testing.assert_array_equal(np.asarray(direct), np.asarray(via_vector))
+    if head_case == _HEAD_NEVER:
+        assert float(direct[0]) == np.inf and float(direct[1]) == 0.0
+    if head_case == _HEAD_FIRST and running.any():
+        assert float(direct[0]) == ends[running].min()
+
+
+def test_direct_reservation_matches_at_the_sweep_width():
+    """Vmapped over 9 scenarios of 2,313 rows (the UPPMAX cell's table):
+    a full 9,720-core machine with a deep backlog, so most rows are
+    queued, end times fall on a minute grid (long tie runs), and the
+    heads range over none, one core, random widths and more than the
+    whole machine. Direct and vector reservations agree bit for bit."""
+    rng = np.random.default_rng(3_200_000_001)
+    b, n, total = 9, 2313, 9720.0
+    cores = rng.integers(1, 160, (b, n)).astype(np.float32)
+    running = np.zeros((b, n), bool)
+    for i in range(b):          # fill each machine, in a random order
+        order = rng.permutation(n)
+        fit = np.cumsum(cores[i, order]) <= total - 8 * i
+        running[i, order[fit]] = True
+    ends = (60.0 * rng.integers(1, 2880, (b, n))).astype(np.float32)
+    free = total - np.where(running, cores, 0.0).sum(axis=1)
+    head = rng.integers(1, int(total), b).astype(np.float32)
+    head[:3] = (0.0, 1.0, total + 1.0)
+    direct, via_vector = jax.jit(jax.vmap(_both_reservations))(
+        jnp.asarray(ends), jnp.asarray(cores), jnp.asarray(running),
+        jnp.asarray(free, jnp.float32), jnp.asarray(head))
+    np.testing.assert_array_equal(np.asarray(direct), np.asarray(via_vector))
+    assert np.isinf(np.asarray(direct[0])[2])
+    assert np.isfinite(np.asarray(direct[0])[3:]).all()
+
+
 _GRID_CFG = XSimConfig(n_warm=8, n_backlog=6, n_arrivals=8, max_stages=9,
                        t0=1800.0)
 
