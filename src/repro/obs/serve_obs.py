@@ -4,11 +4,11 @@
 inside the jitted scan); this module watches the *server*: every request
 through ``serve.loop.ASAServer`` leaves a lifecycle trail
 
-    enqueue → (dedup/defer)* → batch-form → pad → device step →
-    scatter-read → future-resolve
+    enqueue → batch-form → pad → device step → scatter-read →
+    future-resolve
 
 recorded as host-side span events, plus batch-level annotations (batch
-size, pad fraction, deferred-duplicate count, admissions/evictions,
+size, pad fraction, requests left for the next batch, admissions/evictions,
 checkpoint-cadence stalls).  Everything funnels through one
 :class:`ServeObs` object:
 
@@ -81,7 +81,13 @@ def serve_registry() -> Registry:
     c("asa_serve_failed_total", "futures resolved with an error")
     c("asa_serve_observations_total", "requests carrying an observed wait")
     c("asa_serve_deferrals_total",
-      "requests held to a later batch by the dedup batcher")
+      "requests held to a later batch for a same-tenant observation "
+      "(0 since the step folds them in order; kept for its readers)")
+    c("asa_serve_folded_total",
+      "observations applied behind an earlier observation of their slot "
+      "in the same batch")
+    c("asa_serve_fold_rounds_total",
+      "update rounds the decision steps ran, summed over batches")
     c("asa_serve_batches_total", "jitted decision steps dispatched")
     c("asa_serve_decisions_total", "decisions answered (live batch rows)")
     c("asa_serve_padded_rows_total",
@@ -188,6 +194,8 @@ class ServeObs:
         self.c_failed = g.counter("asa_serve_failed_total")
         self.c_observations = g.counter("asa_serve_observations_total")
         self.c_deferrals = g.counter("asa_serve_deferrals_total")
+        self.c_folded = g.counter("asa_serve_folded_total")
+        self.c_fold_rounds = g.counter("asa_serve_fold_rounds_total")
         self.c_batches = g.counter("asa_serve_batches_total")
         self.c_decisions = g.counter("asa_serve_decisions_total")
         self.c_padded = g.counter("asa_serve_padded_rows_total")
@@ -262,13 +270,6 @@ class ServeObs:
         if self.spans:
             self._appended += 1
             self.events.append(("i", "enqueue", SERVE_REQUEST_PID,
-                                tenant, t, 0.0, rid, None))
-
-    def defer(self, rid: int, tenant: int, t: float) -> None:
-        self.c_deferrals.inc()
-        if self.spans:
-            self._appended += 1
-            self.events.append(("i", "defer", SERVE_REQUEST_PID,
                                 tenant, t, 0.0, rid, None))
 
     def resolve(self, rid: int, tenant: int, t_enqueue: float, t: float,

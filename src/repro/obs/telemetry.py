@@ -65,7 +65,7 @@ SERVE_PROFILE_REQUIRED = ("p50_ms", "p99_ms", "decisions_per_sec")
 
 # profile keys a serve_metrics record must carry (batching health:
 # fraction of dispatched rows that were padding, fraction of requests
-# the dedup batcher deferred)
+# the batcher deferred, 0 since the step folds same-tenant observations)
 SERVE_METRICS_PROFILE_REQUIRED = ("pad_fraction", "defer_rate")
 
 # profile keys a serve_chaos record must carry: p99 seconds from fault

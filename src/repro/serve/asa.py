@@ -18,19 +18,28 @@ A query carries (slot, observed_wait?, has_obs):
   stage be submitted": the MAP wait of the (freshly updated) posterior,
   plus the posterior-mean wait and entropy (``asa.posterior_features``).
 
-Batch semantics: observations scatter first, then every decision reads
-the post-scatter table — a request that both observes and decides sees
-its own update.  The host batcher (``repro.serve.loop``) guarantees **at
-most one observation per slot per batch** (duplicates are deferred to
-the next batch), which keeps the scatter well-defined; decisions are
-pure reads, so duplicate decision slots are fine.
+Batch semantics — **serial equivalence**: every answer of a batch
+equals what the server would give if it served the batch's requests one
+at a time, in row (= arrival) order.  A request that observes reads its
+slot's posterior after its own observation and every earlier one of its
+slot; a decide-only request reads it after the observations of its slot
+that precede it.  Several observations of one slot in a batch are
+**folded in order**: the step ranks each observing row within its slot
+by row order and applies rank r in round r, so every round updates
+unique slots and consumes each slot's PRNG key once per observation, as
+a sequential chain (``asa.learn_wait_if`` is not a sum).  The number of
+rounds is the largest count of observations any slot has in the batch,
+a loop trip count read from the data: nothing recompiles per batch, and
+a batch with no repeated observing slot runs the one round it always
+did.  The fold runs once: the update step keeps the state each row
+answers from, and the read computes every answer from those rows.
 
-The ``mesh=`` path shard_maps the *query* axis over a 1-D ``scenarios``
-mesh with the table replicated: each device updates its block of query
-rows, all-gathers the updated rows, and applies the identical full-batch
-scatter — so every device holds the same new table and the result is
-bit-identical to the single-device vmap path (pinned by
-tests/test_serve_sharded.py on 1/2/4/8 fake devices).
+The ``mesh=`` path shard_maps each round's per-row updates over a 1-D
+``scenarios`` mesh with the table and the batch replicated: each device
+updates its block of rows, all-gathers the updated rows, and every
+device applies the identical full-batch scatter — so every device holds
+the same new table and answer rows, and the result is bit-identical to
+the single-device vmap path (pinned by tests/test_serve_sharded.py).
 
 Everything here is pure/functional; threads, queues, tenant admission
 and checkpoint cadence live in ``repro.serve.loop``.
@@ -96,107 +105,154 @@ def reset_slot(table: asa.ASAState, slot: jax.Array,
     return jax.tree.map(lambda t, f: t.at[slot].set(f), table, fresh)
 
 
-def _update_body(table: asa.ASAState, q: QueryBatch, mask: jax.Array,
-                 scatter_rows=None) -> asa.ASAState:
-    """Apply the batch's observations to the table.
+class _Fold(NamedTuple):
+    """Per-row bookkeeping of one batch's in-order fold (all (B,))."""
 
-    ``scatter_rows`` post-processes the locally-updated rows before the
-    scatter — the sharded path all-gathers them so every device applies
-    the identical full-batch write; the vmap path scatters them as-is.
-    Its device work is named ``asa.update`` for the profiler.
-    """
-    m = table.log_p.shape[-1]
-    n = table.log_p.shape[0]
-    bins = jnp.asarray(make_bins(m), jnp.float32)
-    with jax.named_scope("asa.update"):
-        slot = jnp.clip(q.slot, 0, n - 1)
+    slot: jax.Array    # i32 table row of each query (clipped)
+    do: jax.Array      # the row carries a live observation
+    rank: jax.Array    # observations of its slot in rows before it
+    prev: jax.Array    # the last of those rows, else the row itself
+    last: jax.Array    # no later row observes its slot (observers scatter)
+    rounds: jax.Array  # () rounds to run: observations of the busiest slot
 
-        # observations: gather each query's row, apply the tuned §4.5
-        # update where the query carries one (learn_wait_if is a no-op —
-        # PRNG included — on the False branch)
-        rows = jax.tree.map(lambda x: x[slot], table)
-        do = mask & q.has_obs
-        upd = jax.vmap(asa.learn_wait_if, in_axes=(0, None, 0, 0))(
-            rows, bins, q.observed_wait, do)
 
-        # scatter the updated rows back; non-observing rows target index
-        # n (mode="drop"), so only real observations touch the table
-        tgt = jnp.where(do, slot, n)
-        if scatter_rows is not None:
-            tgt, upd = scatter_rows(tgt, upd)
+def _plan(q: QueryBatch, mask: jax.Array, n: int) -> _Fold:
+    """Rank every row against the observing rows of its slot (B²
+    compares: the batch is a few hundred rows, the table tens of
+    thousands)."""
+    slot = jnp.clip(q.slot, 0, n - 1)
+    do = mask & q.has_obs
+    i = jnp.arange(slot.shape[0])
+    same = (slot[:, None] == slot[None, :]) & do[None, :]  # [row, j]
+    before = same & (i[None, :] < i[:, None])
+    rank = jnp.sum(before, axis=1, dtype=jnp.int32)
+    prev = jnp.max(jnp.where(before, i[None, :], -1), axis=1)
+    later = jnp.any(same & (i[None, :] > i[:, None]), axis=1)
+    return _Fold(slot=slot, do=do, rank=rank,
+                 prev=jnp.where(prev < 0, i, prev), last=do & ~later,
+                 rounds=jnp.maximum(jnp.max(jnp.where(do, rank + 1, 0)), 1))
+
+
+_learn_rows = jax.vmap(asa.learn_wait_if, in_axes=(0, None, 0, 0))
+
+
+def _fold(table: asa.ASAState, q: QueryBatch, plan: _Fold,
+          learn=_learn_rows) -> asa.ASAState:
+    """Each row's state after its own observation (observers) or its
+    pre-batch row (decide-only rows): round r applies the rank-r
+    observations to the state their previous observer left in round
+    r - 1.
+
+    ``learn(rows, bins, waits, do)`` updates every row of the batch
+    where ``do`` holds (``learn_wait_if`` is a no-op, PRNG included, on
+    the False branch); the sharded path splits it over the mesh."""
+    bins = jnp.asarray(make_bins(table.log_p.shape[-1]), jnp.float32)
+    rows = jax.tree.map(lambda x: x[plan.slot], table)
+    # round 0 updates each slot's first observer from its own row
+    rows = learn(rows, bins, q.observed_wait, plan.do & (plan.rank == 0))
+
+    def round_r(r, cur):
+        on = plan.do & (plan.rank == r)
+        upd = learn(jax.tree.map(lambda x: x[plan.prev], cur), bins,
+                    q.observed_wait, on)
         return jax.tree.map(
-            lambda t, u: t.at[tgt].set(u, mode="drop"), table, upd)
+            lambda u, c: jnp.where(on.reshape((-1,) + (1,) * (u.ndim - 1)),
+                                   u, c), upd, cur)
+
+    return jax.lax.fori_loop(1, plan.rounds, round_r, rows)
+
+
+def _update_body(table: asa.ASAState, q: QueryBatch, mask: jax.Array,
+                 learn=_learn_rows):
+    """Fold the batch's observations once. Returns the new table (each
+    slot's state is that of its last observer, one unique-slot
+    scatter), and the fold's rows with the row each query answers from.
+    Its device work is named ``asa.update`` for the profiler."""
+    n = table.log_p.shape[0]
+    with jax.named_scope("asa.update"):
+        plan = _plan(q, mask, n)
+        rows = _fold(table, q, plan, learn)
+        # only each slot's last observer writes; the other rows target
+        # index n (mode="drop") and never touch the table
+        tgt = jnp.where(plan.last, plan.slot, n)
+        new = jax.tree.map(
+            lambda t, u: t.at[tgt].set(u, mode="drop"), table, rows)
+        # an observer answers from its own state, a decide-only row from
+        # its slot's last observer before it (or its pre-batch row)
+        src = jnp.where(plan.do, jnp.arange(tgt.shape[0]), plan.prev)
+        return new, rows, src
 
 
 _apply_updates = jax.jit(_update_body)
 
 
 @jax.jit
-def _read_decisions(table: asa.ASAState, q: QueryBatch) -> DecisionBatch:
-    """Answer every query row from the (post-scatter) table.
+def _read_decisions(rows: asa.ASAState, src: jax.Array) -> DecisionBatch:
+    """Answer every query row from the fold's row ``src`` names.
 
     Deliberately its own compiled program, shared by the vmap and the
     shard_map paths: the posterior-mean ⟨p, θ⟩ is a float reduction, and
     XLA may vectorize the same reduction differently at different batch
     widths (a 1-ULP wiggle) — running the one full-batch program on the
-    replicated table makes the sharded decisions bit-identical to the
+    replicated rows makes the sharded decisions bit-identical to the
     single-device ones by construction, not by luck. Its device work is
     named ``asa.read`` for the profiler.
     """
-    m = table.log_p.shape[-1]
-    n = table.log_p.shape[0]
-    bins = jnp.asarray(make_bins(m), jnp.float32)
+    bins = jnp.asarray(make_bins(rows.log_p.shape[-1]), jnp.float32)
     with jax.named_scope("asa.read"):
-        slot = jnp.clip(q.slot, 0, n - 1)
-        fresh = jax.tree.map(lambda x: x[slot], table)
+        at = jax.tree.map(lambda x: x[src], rows)
         feats = jax.vmap(asa.posterior_features, in_axes=(0, None))(
-            fresh, bins)
+            at, bins)
         return DecisionBatch(
             lead_s=feats[:, 0], expected_s=feats[:, 1], entropy=feats[:, 2])
 
 
 def decision_step(table: asa.ASAState, q: QueryBatch, mask: jax.Array
                   ) -> tuple[asa.ASAState, DecisionBatch]:
-    """One batched decision step (single-device vmap path): scatter the
-    observations, then answer every query from the post-scatter table —
-    a query that both observes and decides sees its own update.
+    """One batched decision step (single-device vmap path): fold the
+    observations into the table, and answer every query as if the
+    batch were served one request at a time (module docstring).
 
     ``mask`` is the validity mask from ``parallel.fleet.pad_batch`` —
     pad rows (copies of query 0) never update the table and their
     decision rows are garbage to be sliced off by the caller.
     """
-    table = _apply_updates(table, q, mask)
-    return table, _read_decisions(table, q)
+    table, rows, src = _apply_updates(table, q, mask)
+    return table, _read_decisions(rows, src)
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_update_fn(mesh):
-    """Compiled shard_map of the update half for one mesh (cached, as
-    ``xsim.events._sharded_sweep_fn`` caches its sweeps). Only the
-    per-row posterior updates are sharded; the decision read runs in
-    the shared ``_read_decisions`` program afterwards."""
+    """Compiled shard_map of the fold for one mesh (cached, as
+    ``xsim.events._sharded_sweep_fn`` caches its sweeps). Only each
+    round's per-row posterior updates are sharded; the decision read
+    runs in the shared ``_read_decisions`` program afterwards."""
     from repro.parallel import fleet as pfleet
 
-    spec = pfleet.shard_spec()
     rep = pfleet.replicated_spec()
+    k = mesh.shape[pfleet.SCENARIO_AXIS]
 
-    def block(table: asa.ASAState, q: QueryBatch, mask: jax.Array):
-        def gather_all(tgt, upd):
-            # every device applies the FULL batch's scatter so the
-            # replicated table stays identical everywhere — tiled
-            # all_gather concatenates the blocks in mesh order, i.e. the
-            # original batch order, so the write is bit-identical to the
-            # single-device scatter
-            tgt = jax.lax.all_gather(tgt, pfleet.SCENARIO_AXIS, tiled=True)
-            upd = jax.tree.map(
-                lambda x: jax.lax.all_gather(
-                    x, pfleet.SCENARIO_AXIS, tiled=True), upd)
-            return tgt, upd
+    def learn_block(rows, bins, waits, do):
+        # this device updates its block of rows; the tiled all_gather
+        # concatenates the blocks in mesh order, i.e. batch order, so
+        # every device continues from the identical full-batch rows
+        size = do.shape[0] // k
+        start = jax.lax.axis_index(pfleet.SCENARIO_AXIS) * size
 
-        return _update_body(table, q, mask, scatter_rows=gather_all)
+        def block(x):
+            return jax.lax.dynamic_slice_in_dim(x, start, size)
 
-    fn = jax.shard_map(block, mesh=mesh, in_specs=(rep, spec, spec),
-                       out_specs=rep, check_vma=False)
+        upd = _learn_rows(jax.tree.map(block, rows), bins, block(waits),
+                          block(do))
+        return jax.tree.map(
+            lambda x: jax.lax.all_gather(x, pfleet.SCENARIO_AXIS,
+                                         tiled=True), upd)
+
+    def body(table: asa.ASAState, q: QueryBatch, mask: jax.Array):
+        return _update_body(table, q, mask, learn=learn_block)
+
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(rep, rep, rep),
+                       out_specs=(rep,) * 3, check_vma=False)
     return jax.jit(fn)
 
 
@@ -227,5 +283,5 @@ def serve_step(table: asa.ASAState, q: QueryBatch, mask: jax.Array, *,
     bit for bit."""
     if mesh is None:
         return decision_step(table, q, mask)
-    table = _sharded_update_fn(mesh)(table, q, mask)
-    return table, _read_decisions(table, q)
+    table, rows, src = _sharded_update_fn(mesh)(table, q, mask)
+    return table, _read_decisions(rows, src)

@@ -23,11 +23,14 @@ Host-side responsibilities (everything the jitted core must not know):
   sheds the *coldest* idle tenant (oldest lease deadline) instead of
   erroring; tenants with rows already in the forming batch are never
   shed (one slot must not serve two tenants inside one scatter).
-* **observation dedup** — the decision core requires at most one
-  observation per slot per batch (the scatter must be well-defined).
-  The batcher defers a tenant's second same-batch observation — and
-  every later request of that tenant, preserving per-tenant order — to
-  the next batch.
+* **observation order** — a batch may hold several observations of one
+  tenant: the decision core folds them in row order, so the batcher
+  keeps arrival order and defers nothing for them. The loop counts the
+  fold's work from the batch's slots, which it holds on the host (a
+  read of the step's own plan would cost a second device→host transfer
+  per batch): observations applied behind an earlier one of their slot
+  (``asa_serve_folded_total``) and the rounds each step runs
+  (``asa_serve_fold_rounds_total``).
 * **checkpoint cadence** — every ``checkpoint_every`` batches the server
   snapshots ``{table, tenant_ids, admissions, dirty}`` through
   ``runtime.checkpoint``
@@ -424,10 +427,9 @@ class ASAServer:
         o.span("idle", idle.t0, idle.t1)
 
     def _drain(self) -> list[tuple[Request, Future]]:
-        """Pull queued requests into the deferred deque, then pick the
-        next batch in order — shedding expired-deadline requests, and
-        deferring any tenant whose second same-batch observation would
-        break the unique-scatter invariant."""
+        """Pull queued requests into the deferred deque, then take the
+        next batch in arrival order, shedding expired-deadline
+        requests; what does not fit waits for the next batch."""
         pending = self._deferred
         while True:
             try:
@@ -435,12 +437,8 @@ class ASAServer:
             except queue.Empty:
                 break
         batch: list[tuple[Request, Future]] = []
-        held: deque[tuple[Request, Future]] = deque()
-        obs_seen: set[int] = set()
-        blocked: set[int] = set()
         o = self._obs
-        t_d = o.now()  # one defer timestamp per drain: deferral events
-        #                are batch-granular, a clock read each is not free
+        t_d = o.now()  # one shed timestamp per drain
         now_mono = time.monotonic()  # one deadline check point per drain
         while pending and len(batch) < self.cfg.batch_size:
             req, fut = pending.popleft()
@@ -455,23 +453,8 @@ class ASAServer:
                 o.resolve(req.rid, req.tenant, req.t_enqueue, t_d,
                           error="expired")
                 continue
-            if req.tenant in blocked:
-                o.defer(req.rid, req.tenant, t_d)
-                held.append((req, fut))
-                continue
-            if req.observed_wait is not None:
-                if req.tenant in obs_seen:
-                    # second observation for this slot: defer it (and all
-                    # later requests of this tenant — order preserved)
-                    blocked.add(req.tenant)
-                    o.defer(req.rid, req.tenant, t_d)
-                    held.append((req, fut))
-                    continue
-                obs_seen.add(req.tenant)
             batch.append((req, fut))
-        held.extend(pending)
-        self._deferred = held
-        o.g_deferred.set(len(held))
+        o.g_deferred.set(len(pending))
         return batch
 
     def _admit_batch(self, batch: list[tuple[Request, Future]]):
@@ -597,6 +580,7 @@ class ASAServer:
             self._last_batch_ts = time.monotonic()
             o.c_batches.inc()
             o.c_decisions.inc(len(live))
+            _count_fold(o, slots[has])
             o.c_padded.inc(self.cfg.batch_size - len(live))
         if o.spans:
             fill = len(live) / self.cfg.batch_size
@@ -936,6 +920,16 @@ class ASAServer:
             "restarts": int(o.c_restarts.value),
             "lease_evictions": int(o.c_lease_evictions.value),
         }
+
+
+def _count_fold(o: ServeObs, obs_slots: np.ndarray) -> None:
+    """The fold's work in one dispatched batch, from its observing rows'
+    slots, as ``serve.asa._plan`` sets it: one round always, one more
+    per further observation of the busiest slot; every observation
+    behind the first of its slot is folded."""
+    per_slot = np.unique(obs_slots, return_counts=True)[1]
+    o.c_fold_rounds.inc(int(per_slot.max(initial=1)))
+    o.c_folded.inc(int(obs_slots.size - per_slot.size))
 
 
 class ServeSupervisor:

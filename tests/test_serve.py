@@ -109,38 +109,71 @@ def test_pad_rows_never_touch_the_table():
 
 
 # -------------------------------------------------------------- batching
-def test_duplicate_observation_defers_preserving_order():
-    """Second same-batch observation of a tenant (and all its later
-    requests) defer to the next batch; both updates still apply, in
-    submission order."""
-    server = ASAServer(_cfg(batch_size=8))
-    f1 = server.submit(3, observed_wait=100.0)
-    f2 = server.submit(3, observed_wait=200.0)
-    f3 = server.submit(3)                       # decide after both
-    n = server.step_once(wait_s=0)
-    assert n == 1 and f1.done() and not f2.done() and not f3.done()
-    n = server.step_once(wait_s=0)
-    assert n == 2 and f2.done() and f3.done()
-    # reference: the same two updates applied sequentially to one row
-    ref = core_asa.init(53, _slot_key(server, 3))
-    assert f3.result().lead_s == pytest.approx(_two_step_map(ref))
+def _mixed_stream(seed, n=16):
+    """Requests of a few tenants, tenant 3 observing up to 6 times,
+    interleaved with decide-only requests of the same tenants."""
+    rng = np.random.default_rng(seed)
+    tenants = rng.choice([1, 3, 3, 3, 5], size=n)
+    waits = rng.uniform(20.0, 9000.0, n)
+    obs = rng.random(n) < 0.6
+    obs[np.flatnonzero((tenants == 3) & obs)[6:]] = False
+    return [(int(t), float(w) if o else None)
+            for t, w, o in zip(tenants, waits, obs)]
 
 
-def _slot_key(server, tenant):
-    # fresh slots keep their init_table key; recompute tenant's row key
-    slot = server._slot_of[tenant]
-    fresh = serve_asa.init_table(server.cfg.n_slots, server.cfg.m,
-                                 server.cfg.seed)
-    return fresh.key[slot]
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_same_slot_observations_fold_as_served_one_by_one(seed):
+    """Serial equivalence: one batch holding several observations of a
+    slot answers what a server taking one request per batch answers,
+    and leaves the same table."""
+    reqs = _mixed_stream(seed)
+    assert sum(t == 3 and w is not None for t, w in reqs) >= 2
+    batched = ASAServer(_cfg(batch_size=16))
+    futs = [batched.submit(t, w) for t, w in reqs]
+    assert batched.step_once(wait_s=0) == len(reqs)      # one batch
+    single = ASAServer(_cfg(batch_size=1))
+    ones = []
+    for t, w in reqs:
+        ones.append(single.submit(t, w))
+        single.step_once(wait_s=0)
+    for a, b in zip(jax.tree.leaves(batched._table),
+                    jax.tree.leaves(single._table)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for fa, fb in zip(futs, ones):
+        a, b = fa.result(timeout=10), fb.result(timeout=10)
+        assert a.lead_s == b.lead_s
+        assert a.expected_s == pytest.approx(b.expected_s, rel=1e-6)
+        assert a.entropy == pytest.approx(b.entropy, rel=1e-6)
+    assert batched.obs.c_deferrals.value == 0
 
 
-def _two_step_map(state):
-    bins = jnp.asarray(BINS, jnp.float32)
-    s = core_asa.learn_wait_if(state, bins, jnp.float32(100.0),
-                               jnp.asarray(True))
-    s = core_asa.learn_wait_if(s, bins, jnp.float32(200.0),
-                               jnp.asarray(True))
-    return float(core_asa.map_wait(s, bins))
+def test_zipf_traffic_matches_the_reference():
+    """Seeded Zipf-skewed tenants through ``submit`` on 64 slots, in
+    batches that repeat the hottest tenants' observations: every answer
+    against Algorithm 1 replayed per tenant, held to serial equivalence
+    (the Zipf cell's check)."""
+    from chipbench.drivers import decide_zipf
+    from chipbench.generator import Requests
+
+    n_ten, n = 48, 400
+    rng = np.random.default_rng(16)
+    p = 1.0 / np.arange(1, n_ten + 1) ** 0.99
+    tenant = rng.permutation(n_ten)[rng.choice(n_ten, n, p=p / p.sum())]
+    waits = np.clip(rng.lognormal(8.0, 0.7, n), 1.0, 1e5).astype(np.float32)
+    reqs = Requests(np.zeros(n), tenant,
+                    np.where(rng.random(n) < 0.5, waits, np.nan))
+    server = ASAServer(_cfg(n_slots=64, batch_size=32, seed=5))
+    _decide(server, list(range(n_ten)))       # tenant i holds slot i
+    futs = [server.submit(int(t), None if np.isnan(w) else float(w))
+            for t, w in zip(reqs.tenant, reqs.wait_s)]
+    while any(not f.done() for f in futs):
+        server.step_once(wait_s=0)
+    assert server.obs.c_folded.value > 0
+    out = {"reqs": reqs, "results": [f.result() for f in futs],
+           "failed": 0, "ctx": {"slot_seed": 5}}
+    cfg = {"serve": {"n_slots": 64, "tenants": n_ten}}
+    checks = decide_zipf.check(out, cfg, np.arange(n_ten))
+    assert checks.ok, checks.as_dict()
 
 
 def test_table_full_fails_the_future_not_the_loop():

@@ -232,19 +232,26 @@ def test_span_conservation_eviction_race():
     assert o.g_inflight.value == 0
 
 
-def test_deferred_duplicates_conserve_and_count():
-    server = ASAServer(_cfg(obs_spans=True, batch_size=8))
-    f1 = server.submit(3, observed_wait=100.0)
-    f2 = server.submit(3, observed_wait=200.0)  # same-batch duplicate
-    f3 = server.submit(3)
-    _drain_all(server, [f1, f2, f3])
+def test_fold_counters_conserve():
+    """One batch with 3, 2 and 1 observations of three tenants folds 3
+    observations in 3 rounds; a decide-only batch runs its one round;
+    nothing is deferred, and every request still resolves exactly
+    once."""
+    server = ASAServer(_cfg(obs_spans=True, batch_size=16))
+    reqs = [(3, 100.0), (5, 80.0), (3, None), (3, 200.0), (1, 50.0),
+            (5, 90.0), (3, 300.0), (1, None)]
+    futs = [server.submit(t, w) for t, w in reqs]
+    assert server.step_once(wait_s=0) == len(reqs)
+    futs += [server.submit(t) for t in (1, 3)]
+    _drain_all(server, futs)
     o = server.obs
+    assert int(o.c_batches.value) == 2
+    assert int(o.c_folded.value) == 3
+    assert int(o.c_fold_rounds.value) == 3 + 1
+    assert int(o.c_deferrals.value) == 0 and "defer" not in _tally(o)
     assert sorted(_request_rids(o, "enqueue")) == \
         sorted(_request_rids(o, "request"))
-    # f2 deferred once, f3 deferred behind it (order preserved)
-    assert int(o.c_deferrals.value) == _tally(o)["defer"] == 2
-    r = o.rates()
-    assert r["defer_rate"] == pytest.approx(2 / 3)
+    assert o.rates()["defer_rate"] == 0.0
 
 
 # ------------------------------------------------- bit-identity + stats

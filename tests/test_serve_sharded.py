@@ -5,6 +5,12 @@ path.  CI's ``xsim-sharded`` job fakes 8 CPU devices with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
 
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -22,21 +28,15 @@ needs = pytest.mark.skipif  # readability alias for the device gates
 
 
 def _query(n, seed=0):
-    """A busy batch: repeated decision slots, unique observation slots
-    (the invariant the host batcher guarantees)."""
+    """A busy batch: repeated decision slots, and slots observed several
+    times (the step folds them in row order)."""
     rng = np.random.default_rng(seed)
     slot = rng.integers(0, 12, n).astype(np.int32)
-    has = np.zeros(n, bool)
-    seen = set()
-    for i in range(n):
-        if int(slot[i]) not in seen and rng.random() < 0.7:
-            seen.add(int(slot[i]))
-            has[i] = True
     return serve_asa.QueryBatch(
         slot=jnp.asarray(slot),
         observed_wait=jnp.asarray(
             rng.uniform(20.0, 3000.0, n).astype(np.float32)),
-        has_obs=jnp.asarray(has))
+        has_obs=jnp.asarray(rng.random(n) < 0.7))
 
 
 def _assert_tables_equal(a, b):
@@ -127,3 +127,41 @@ def test_sharded_restart_bitwise(tmp_path):
         assert (a.lead_s, a.expected_s, a.entropy) == \
                (b.lead_s, b.expected_s, b.entropy)
     _assert_tables_equal(server._table, restored._table)
+
+
+FOUR = textwrap.dedent("""
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_scenarios_mesh
+    from repro.parallel import fleet as pfleet
+    from repro.serve import asa as serve_asa
+
+    assert len(jax.devices()) == 4
+    mesh = make_scenarios_mesh(4)
+    table = serve_asa.init_table(16, seed=4)
+    slot = np.array([2] * 6 + [5, 2, 7, 5, 2, 9] * 3, np.int32)
+    q = serve_asa.QueryBatch(
+        slot=jnp.asarray(slot),
+        observed_wait=jnp.asarray(np.linspace(30.0, 4000.0, slot.size,
+                                              dtype=np.float32)),
+        has_obs=jnp.asarray(np.arange(slot.size) % 4 != 3))
+    qp, mask = pfleet.pad_batch(q, 32)
+    ref_t, ref_d = serve_asa.serve_step(table, qp, mask)
+    sh_t, sh_d = serve_asa.serve_step(table, qp, mask, mesh=mesh)
+    for a, b in zip(jax.tree.leaves((ref_t, ref_d)),
+                    jax.tree.leaves((sh_t, sh_d))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    print("bit-identical")
+""")
+
+
+def test_sharded_fold_bit_identical_on_four_devices():
+    """A batch observing one slot up to 10 times, on four virtual
+    devices in a child process: the shard_map fold writes the vmap
+    fold's table and answers bit for bit."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", FOUR], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert "bit-identical" in p.stdout
